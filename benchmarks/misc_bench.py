@@ -782,6 +782,9 @@ def main(force: bool = False) -> Dict:
 
 if __name__ == "__main__":
     import argparse
+
+    from repro.launch.cli import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="tiny uncached end-to-end engine exercise (CI)")

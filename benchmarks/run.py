@@ -192,4 +192,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.cli import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -1,19 +1,4 @@
-"""Pytest bootstrap: make ``src/`` importable and install compat shims.
-
-Running via the tier-1 command (``PYTHONPATH=src python -m pytest``) already
-loads ``src/sitecustomize.py`` at interpreter startup; this conftest makes a
-bare ``pytest`` invocation equivalent — it prepends ``src`` to ``sys.path``
-and installs the same hooks (idempotent):
-
-  * the lazy ``jax.shard_map`` compat alias (``repro.compat``), and
-  * the fallback finder serving vendored stand-ins for missing optional
-    dependencies (e.g. ``hypothesis`` -> ``repro._vendor.minihypothesis``).
-
-The uniquely named ``_repro_bootstrap`` is imported (rather than
-``sitecustomize``) so this works even on Pythons whose distribution ships
-its own ``sitecustomize`` module, which would already occupy the name in
-``sys.modules`` and make the import a silent no-op.
-"""
+"""Pytest bootstrap: make ``src/`` importable for a bare ``pytest`` run."""
 
 import os
 import sys
@@ -21,7 +6,3 @@ import sys
 SRC = os.path.join(os.path.dirname(os.path.abspath(__file__)), "src")
 if SRC not in sys.path:
     sys.path.insert(0, SRC)
-
-import _repro_bootstrap  # noqa: E402
-
-_repro_bootstrap.install()

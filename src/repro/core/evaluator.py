@@ -27,7 +27,7 @@ from __future__ import annotations
 
 from collections import OrderedDict
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -65,6 +65,11 @@ CACHE_STATS: Dict[str, int] = {
     "group_eval_fused.evictions": 0,
 }
 _obs_metrics.register_collector(lambda: dict(CACHE_STATS))
+
+# Fused-pass activity, process-wide like CACHE_STATS: jitted calls made and
+# the platforms their results lived on — how a caller checks that the
+# scoring pass really left the host.
+FUSED_STATS: Dict[str, Any] = {"calls": 0, "platforms": set()}
 
 
 @dataclass
@@ -422,6 +427,8 @@ class Evaluator:
                 self._has_d2d, self.arch)
         delay, energy, stage, overflow, b_idx, eparts = \
             self._fused_fn(B, idx, vals, npass, dep, wts)
+        FUSED_STATS["calls"] += 1
+        FUSED_STATS["platforms"].update(d.platform for d in delay.devices())
         delay = np.asarray(delay)
         energy = np.asarray(energy)
         stage = np.asarray(stage)

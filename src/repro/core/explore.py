@@ -1061,6 +1061,14 @@ class ExplorationEngine:
                     "registered name, or trace spec — see repro.serve.slo)")
             resolve_traffic(cfg.traffic)
         self.n_workers = max(1, int(n_workers))
+        if cfg.sa.backend == "jax" and self.n_workers > 1:
+            # each spawned worker would open the accelerator, and a chip
+            # belongs to one process at a time
+            raise ValueError(
+                f"n_workers={self.n_workers} with SAConfig(backend='jax'): "
+                f"every worker process would open the accelerator, which "
+                f"one process holds at a time; run the fused scorer with "
+                f"n_workers=1")
         self.checkpoint = checkpoint
         self.progress = progress
         self.mp_context = mp_context

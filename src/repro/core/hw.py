@@ -10,7 +10,8 @@ Two constant sets live here:
     calibrated against the publications the paper cites (GRS D2D 1.17 pJ/b
     [Poulton'19], on-chip lines <0.1 pJ/b, GDDR6 32 GB/s per $3.5 die,
     Yield_unit=0.9 per 40 mm^2 [Chiplet Actuary]).
-  * ``TPU_V5E``    — roofline constants for the JAX/TPU side of this repo.
+  * ``TPU_CHIPS``  — roofline peaks of the TPU chips the JAX side runs on,
+    keyed by the ``device_kind`` JAX reports (``chip_for``).
 """
 
 from __future__ import annotations
@@ -18,7 +19,7 @@ from __future__ import annotations
 import dataclasses
 from dataclasses import dataclass
 from functools import cached_property
-from typing import Tuple
+from typing import Dict, Tuple
 
 
 # --------------------------------------------------------------------------
@@ -96,6 +97,21 @@ class TPUChip:
 
 
 TPU_V5E = TPUChip()
+
+# Published per-chip peaks (Google Cloud documentation, "TPU v5e"), keyed
+# by ``jax.Device.device_kind``.
+TPU_CHIPS: Dict[str, TPUChip] = {"TPU v5 lite": TPU_V5E}
+
+
+def chip_for(device_kind: str) -> TPUChip:
+    """Roofline peaks of the chip JAX names ``device_kind``; a kind with
+    no published peaks here is an error, never a default."""
+    try:
+        return TPU_CHIPS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no roofline peaks for device_kind {device_kind!r}; known: "
+            f"{sorted(TPU_CHIPS)}") from None
 
 
 # --------------------------------------------------------------------------

@@ -68,8 +68,24 @@ class SAConfig:
     # parity-grade (~1e-4), so trajectories may diverge from the exact
     # engine's — but every chain's BEST mapping is still re-scored by the
     # exact engine in finalize(), so reported costs are always exact
-    # (the rescore-winners contract, DESIGN.md).
+    # (the rescore-winners contract, DESIGN.md).  The fused pass only
+    # scores lockstep replica-exchange proposals, so "jax" requires
+    # n_chains > 1 and lockstep=True; any other config is refused rather
+    # than silently scored on the host.
     backend: str = "numpy"
+
+    def __post_init__(self):
+        if self.backend not in ("numpy", "jax"):
+            raise ValueError(
+                f"unknown SA backend {self.backend!r}: 'numpy' or 'jax'")
+        if self.backend == "jax" and (self.n_chains <= 1
+                                      or not self.lockstep):
+            raise ValueError(
+                f"SAConfig(backend='jax') scores proposals with the fused "
+                f"pass only in lockstep replica exchange; n_chains="
+                f"{self.n_chains}, lockstep={self.lockstep} would score "
+                f"every proposal on the host with NumPy — use n_chains > 1 "
+                f"and lockstep=True, or backend='numpy'")
 
 
 @dataclass

@@ -257,6 +257,16 @@ class Supervisor:
         self._candidates = spec.build_candidates()
         self._workloads = spec.build_workloads()
         self._cfg = spec.build_cfg()
+        if (self._cfg.sa.backend == "jax" and spec.n_shards > 1
+                and any(isinstance(h, LocalProcessHost)
+                        for h in self.hosts)):
+            # concurrent local children would each open this machine's
+            # accelerator, which one process holds at a time
+            raise ValueError(
+                f"{spec.n_shards} shards with SAConfig(backend='jax') on a "
+                f"local host: each shard child would open this machine's "
+                f"accelerator, which one process holds at a time; use one "
+                f"shard, remote hosts, or backend='numpy'")
         self.fingerprint = sweep_fingerprint(self._workloads, self._cfg,
                                              use_sa=spec.use_sa)
 

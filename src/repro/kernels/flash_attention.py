@@ -19,6 +19,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1.0e30
 
@@ -98,19 +99,10 @@ def flash_attention_mha(q: jax.Array, k: jax.Array, v: jax.Array, *,
         out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, nq * bq, D), q.dtype),
         scratch_shapes=[
-            pl_scratch((bq, D)),        # f32 accumulator
-            pl_scratch((bq, 1)),        # running max
-            pl_scratch((bq, 1)),        # running denominator
+            pltpu.VMEM((bq, D), jnp.float32),   # accumulator
+            pltpu.VMEM((bq, 1), jnp.float32),   # running max
+            pltpu.VMEM((bq, 1), jnp.float32),   # running denominator
         ],
         interpret=interpret,
     )(q, k, v)
     return out[:, :, :Sq]
-
-
-def pl_scratch(shape):
-    """VMEM f32 scratch allocation (portable across pallas versions)."""
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-        return pltpu.VMEM(shape, jnp.float32)
-    except Exception:  # pragma: no cover - older pallas
-        return pl.VMEM(shape, jnp.float32)

@@ -1,8 +1,10 @@
 """jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to auto: real TPU lowering on TPU backends, Pallas
-interpret mode elsewhere (this CPU container).  GQA inputs are expanded to
-MHA layout here so the kernels stay MXU-simple.
+``interpret`` defaults to auto: real TPU lowering on a TPU backend, the
+Pallas interpreter on the CPU backend (tests), and an error on any other
+backend — a kernel never silently runs interpreted where a device was
+meant to run it.  GQA inputs are expanded to MHA layout here so the
+kernels stay MXU-simple.
 """
 
 from __future__ import annotations
@@ -21,7 +23,14 @@ from .tiled_matmul import tiled_matmul
 def _auto_interpret(interpret: Optional[bool]) -> bool:
     if interpret is not None:
         return interpret
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"the Pallas kernels target TPU (or the CPU interpreter); the "
+        f"default backend is {backend!r}")
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "n_kv", "bq", "bk",

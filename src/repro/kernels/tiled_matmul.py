@@ -13,8 +13,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from .flash_attention import pl_scratch
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _mm_kernel(a_ref, b_ref, o_ref, acc_ref, *, nk: int):
@@ -57,7 +56,7 @@ def tiled_matmul(a: jax.Array, b: jax.Array, *, bm: int = 256, bn: int = 256,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((nm * bm, nn * bn), out_dtype),
-        scratch_shapes=[pl_scratch((bm, bn))],
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
     )(a, b)
     return out[:M, :N]

@@ -13,7 +13,9 @@ hand-rolled parser, so flags mean the same thing across
   single ``repro.core.workloads.make_workload`` registry; a bare SPEC is
   allowed when the binding target has exactly one workload name.  Unknown
   specs raise ``make_workload``'s preset listing;
-* ``--out PATH`` / ``--seed N`` — artifact path and base RNG seed.
+* ``--out PATH`` / ``--seed N`` — artifact path and base RNG seed;
+* :func:`enable_compile_cache` — the persistent JAX compilation cache,
+  turned on by each entry point's ``__main__`` (never at import).
 
 Import-light on purpose: graph builders and model configs load inside
 the resolver functions, not at module import (drivers pre-parse argv
@@ -23,7 +25,29 @@ before heavyweight imports).
 from __future__ import annotations
 
 import argparse
+import os
+from pathlib import Path
 from typing import Callable, Dict, List, Optional, Sequence
+
+# the compile cache's home when JAX_COMPILATION_CACHE_DIR is unset: a fixed
+# path in the checkout (git-ignored) — the directory is part of what a
+# later run must find again, so it never moves
+DEFAULT_COMPILE_CACHE = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing here names another directory; otherwise the cache lives at
+    :data:`DEFAULT_COMPILE_CACHE`.  Every compile is kept, however short:
+    a realized program is many sub-second stage compiles."""
+    import jax
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir",
+                          str(DEFAULT_COMPILE_CACHE))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return jax.config.jax_compilation_cache_dir
 
 # ---------------------------------------------------------------------------
 # --arch / --reduced

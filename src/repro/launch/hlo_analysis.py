@@ -84,8 +84,10 @@ class Computation:
 
 
 _COMP_HEADER = re.compile(r"^(ENTRY\s+)?%?([\w\.\-]+)\s*\(.*\)\s*->")
+# the result type is a tuple or one space-free token; TPU layouts carry
+# parentheses inside it (``{1,0:T(8,128)S(1)}``)
 _INSTR = re.compile(
-    r"^\s*(?:ROOT\s+)?%?([\w\.\-]+)\s*=\s*(\(.*?\)|[\w\[\]\{\},: ]+?)\s+"
+    r"^\s*(?:ROOT\s+)?%?([\w\.\-]+)\s*=\s*(\(.*?\)|\S+?)\s+"
     r"([\w\-]+)\(")
 _OPERAND = re.compile(r"%([\w\.\-]+)")
 

@@ -1,8 +1,12 @@
 """Roofline-term extraction from compiled XLA artifacts.
 
-compute term    = per-device HLO FLOPs / peak_FLOPs          (197e12 bf16, v5e)
-memory term     = per-device HLO bytes / HBM bw               (819e9 B/s)
-collective term = per-device collective bytes / ICI link bw   (50e9 B/s)
+compute term    = per-device HLO FLOPs / peak_FLOPs
+memory term     = per-device HLO bytes / HBM bw
+collective term = per-device collective bytes / ICI link bw
+
+The peaks are the target chip's, looked up by its ``device_kind`` in
+``core.hw.TPU_CHIPS`` (v5e: 197e12 bf16 FLOP/s, 819e9 B/s HBM, 50e9 B/s
+per ICI link); a kind with no published peaks is refused.
 
 ``cost_analysis()`` on the SPMD-partitioned executable reports *per-device*
 FLOPs/bytes (verified empirically: a 256-way-sharded matmul reports 1/256 of
@@ -19,7 +23,7 @@ import re
 from dataclasses import dataclass, field
 from typing import Dict, Optional
 
-from ..core.hw import TPU_V5E, TPUChip
+from ..core.hw import TPUChip, chip_for
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -67,6 +71,7 @@ def collective_bytes(hlo_text: str) -> Dict[str, int]:
 @dataclass
 class Roofline:
     name: str
+    chip: TPUChip
     flops_per_device: float
     bytes_per_device: float
     coll_bytes_per_device: float
@@ -78,7 +83,6 @@ class Roofline:
     model_flops: float = 0.0           # 6*N*D (or 2*N*D serve), GLOBAL
     n_devices: int = 256
     compile_s: float = 0.0
-    chip: TPUChip = field(default_factory=lambda: TPU_V5E)
 
     @property
     def t_compute(self) -> float:
@@ -193,8 +197,9 @@ def model_flops_for(cfg, shape) -> float:
 
 def analyze_compiled(name: str, compiled, lowered_text: Optional[str],
                      model_flops: float, n_devices: int,
-                     compile_s: float = 0.0) -> Roofline:
-    """Roofline terms from the compiled per-device module.
+                     device_kind: str, compile_s: float = 0.0) -> Roofline:
+    """Roofline terms from the compiled per-device module, against the
+    peaks of the chip JAX names ``device_kind``.
 
     Primary source is the trip-count-aware HLO walker (hlo_analysis) —
     XLA's own cost_analysis counts while bodies once, which would be wrong
@@ -209,6 +214,7 @@ def analyze_compiled(name: str, compiled, lowered_text: Optional[str],
     ma = compiled.memory_analysis()
     return Roofline(
         name=name,
+        chip=chip_for(device_kind),
         flops_per_device=costs.flops,
         bytes_per_device=costs.bytes,
         coll_bytes_per_device=costs.coll_bytes,

@@ -49,4 +49,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from .cli import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -66,8 +66,6 @@ def make_compressed_dp_step(loss_fn, opt_update, mesh, axis: str = "data"):
     """
     from jax.sharding import PartitionSpec as P
 
-    from ..compat import shard_map
-
     def local_step(params, opt, err, batch):
         loss, grads = jax.value_and_grad(loss_fn)(params, batch)
         grads, err = compressed_grad_sync(grads, err, axis)
@@ -76,7 +74,7 @@ def make_compressed_dp_step(loss_fn, opt_update, mesh, axis: str = "data"):
         return params, opt, err, {"loss": loss, **metrics}
 
     rep = P()
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(rep, rep, rep, P(axis)),
         out_specs=(rep, rep, rep, rep),

@@ -16,9 +16,9 @@ Axis correspondence (the bridge contract of ``core/bridge.mesh_as_arch``):
   measured HLO HBM bytes                 <->  predicted DRAM bytes
   measured HLO FLOPs                     <->  2 x predicted MACs
 
-Absolute agreement is not expected — the realized program runs f32 on the
-XLA CPU backend while the cost model prices int8/bf16 dataflows — but the
-*ratios* are stable per technology, which is exactly what
+Absolute agreement is not expected — the realized program runs f32 while
+the cost model prices int8/bf16 dataflows — but the *ratios* are stable
+per technology, which is exactly what
 :mod:`.calibrate` fits.  Everything is per ONE pipeline pass (batch-unit
 batch), matching ``GroupAnalysis``'s per-pass convention.
 
@@ -152,14 +152,10 @@ def _measure_stage(sp: StageProgram) -> Dict[str, float]:
            "hbm_bytes": costs.bytes * n_dev,
            "ici_bytes": costs.coll_bytes * n_dev,
            "coll_by_kind": {k: v * n_dev
-                            for k, v in costs.coll_by_kind.items()},
-           "temp_bytes": 0.0, "arg_bytes": 0.0}
-    try:
-        ma = compiled.memory_analysis()
-        out["temp_bytes"] = float(getattr(ma, "temp_size_in_bytes", 0))
-        out["arg_bytes"] = float(getattr(ma, "argument_size_in_bytes", 0))
-    except Exception:          # backend without memory analysis
-        pass
+                            for k, v in costs.coll_by_kind.items()}}
+    ma = compiled.memory_analysis()
+    out["temp_bytes"] = float(ma.temp_size_in_bytes)
+    out["arg_bytes"] = float(ma.argument_size_in_bytes)
     return out
 
 

@@ -70,6 +70,14 @@ def test_spec_validation():
                   cfg={"sa": {}})
 
 
+def test_supervisor_refuses_local_shards_sharing_the_chip(tmp_path):
+    spec = quick_spec(n_shards=2)
+    spec = SweepSpec.from_dict({**spec.to_dict(), "sa": {
+        **spec.sa, "n_chains": 4, "backend": "jax"}})
+    with pytest.raises(ValueError, match="one process"):
+        Supervisor(spec, tmp_path)
+
+
 def test_fault_spec_grammar():
     assert FaultSpec.parse("kill") == FaultSpec("kill", 1, 0.0)
     assert FaultSpec.parse("stall:3") == FaultSpec("stall", 3, 0.0)

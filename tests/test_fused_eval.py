@@ -154,3 +154,32 @@ def test_jax_replay_matches_bincount_exactly_shaped():
     ref = np.bincount(idx, weights=vals, minlength=64)
     assert out.shape == ref.shape and out.dtype == np.float64
     np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("kw", [dict(n_chains=1),
+                                dict(n_chains=4, lockstep=False),
+                                dict(backend="torch")])
+def test_sa_config_refuses_configs_that_skip_the_fused_pass(kw):
+    with pytest.raises(ValueError):
+        SAConfig(**{"backend": "jax", **kw})
+
+
+def test_engine_refuses_workers_with_fused_backend():
+    from repro.core.dse import DSEConfig
+    from repro.core.explore import ExplorationEngine
+    cfg = DSEConfig(batch=8, sa=SAConfig(iters=4, n_chains=4, backend="jax"))
+    g = make_workload("tf-quick")
+    with pytest.raises(ValueError, match="one process"):
+        ExplorationEngine({"TF": g}, cfg, n_workers=2)
+    ExplorationEngine({"TF": g}, cfg, n_workers=1).close()
+
+
+def test_fused_stats_count_calls_and_platform():
+    from repro.core.evaluator import FUSED_STATS
+    arch = _arch()
+    g = make_workload("tf-quick")
+    before = FUSED_STATS["calls"]
+    Evaluator(arch, g).eval_requests_batch(_requests(g, arch), 8,
+                                           backend="jax")
+    assert FUSED_STATS["calls"] == before + 1
+    assert FUSED_STATS["platforms"] == {"cpu"}

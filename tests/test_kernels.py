@@ -158,3 +158,13 @@ def test_tiled_matmul_bf16():
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(expected, np.float32),
                                atol=0.5, rtol=5e-2)
+
+
+def test_auto_interpret_only_on_cpu(monkeypatch):
+    assert ops._auto_interpret(None) is True          # this CPU backend
+    assert ops._auto_interpret(False) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops._auto_interpret(None) is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        ops._auto_interpret(None)

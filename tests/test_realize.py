@@ -317,3 +317,22 @@ def test_calibrated_sweep_resumable(tmp_path):
     first = run_dse(cands, {"TF": g}, cfg, checkpoint=ck)
     again = run_dse(cands, {"TF": g}, cfg, checkpoint=ck)
     assert [p.objective for p in first] == [p.objective for p in again]
+
+
+def test_split_attention_pair_consumes_probabilities():
+    """With qk and av in different stages, the scores layer applies the
+    softmax the fused flash route applies: its cube holds probabilities
+    and a deep realized chain stays finite."""
+    from repro.realize.program import build_program
+    arch = ArchConfig(x_cores=1, y_cores=1, noc_bw=32.0, d2d_bw=16.0,
+                      dram_bw=64.0, glb_kb=512, macs_per_core=1024)
+    g = transformer(n_layers=4, d_model=64, d_ff=128, seq=64, name="tf-d")
+    groups = [LayerGroup(names=(n,), batch_unit=2) for n in g.topo_order()]
+    plan = lms_to_plan(tangram_map(groups, g, arch))
+    prog = build_program(g, plan, use_pallas=False)
+    routes = {n: r for sp in prog.stages for n, r in sp.routes.items()}
+    assert routes["l0_qk"] == "scores" and routes["l0_av"] == "matmul"
+    out = prog.execute(seed=0)["outputs"]
+    np.testing.assert_allclose(np.asarray(out["l0_qk"]).sum(-1), 1.0,
+                               rtol=1e-5)
+    assert all(np.isfinite(np.asarray(v)).all() for v in out.values())

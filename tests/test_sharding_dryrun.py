@@ -45,7 +45,8 @@ def test_small_mesh_train_compile_and_roofline():
             b = make_cell(cfg, shape, mesh)
             compiled = b.fn.lower(*b.args).compile()
         rl = analyze_compiled("t", compiled, None,
-                              model_flops_for(cfg, shape), 8)
+                              model_flops_for(cfg, shape), 8,
+                              "TPU v5 lite")
         rec = rl.to_dict()
         print(json.dumps({"flops": rec["flops_per_device"],
                           "coll": rec["coll_bytes_per_device"],
@@ -125,3 +126,41 @@ def test_production_mesh_requires_devices():
     from repro.launch.mesh import make_production_mesh
     with pytest.raises(RuntimeError):
         make_production_mesh()           # this process has 1 CPU device
+
+
+def test_roofline_peaks_by_device_kind():
+    from repro.core.hw import TPU_V5E, chip_for
+    assert chip_for("TPU v5 lite") is TPU_V5E
+    with pytest.raises(ValueError, match="cpu"):
+        chip_for("cpu")
+
+
+@pytest.mark.parametrize("env_dir", [True, False])
+def test_compile_cache_dir(tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR wins when set and receives the entries;
+    otherwise the cache goes to the fixed in-checkout directory."""
+    env = {**os.environ, "JAX_PLATFORMS": "cpu",
+           "PYTHONPATH": str(REPO / "src")}
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+    code = textwrap.dedent("""
+        import os
+        from repro.launch.cli import DEFAULT_COMPILE_CACHE, enable_compile_cache
+        d = enable_compile_cache()
+        if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            import jax, jax.numpy as jnp
+            jax.jit(lambda x: x * 3 + 1)(jnp.ones(4)).block_until_ready()
+        else:
+            assert d == str(DEFAULT_COMPILE_CACHE), d
+        print(d)
+    """)
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, timeout=120, env=env)
+    assert out.returncode == 0, out.stderr[-2000:]
+    d = out.stdout.split()[-1]
+    if env_dir:
+        assert d == str(tmp_path)
+        assert any(p.name.startswith("jit_") for p in tmp_path.iterdir())
+    else:
+        assert d == str(REPO / ".jax_cache")
