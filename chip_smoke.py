@@ -50,15 +50,19 @@ FUSED_REL_TOL = 1e-4          # fused pass envelope (DESIGN.md, jax caveats)
 # under jax_default_matmul_precision="highest": each f32 dot — in the
 # Pallas kernels (Mosaic's fp32 contract precision) and in XLA's jnp
 # oracles — is computed on the MXU to f32 grade by multi-pass bf16
-# emulation rather than a single bf16 pass.  The cheapest such scheme
-# (3 passes) drops the lo*lo partial products, a relative error of at most
-# ~2**-16 per product; the two sides differ by at most twice that.  Their
-# f32 accumulation orders also differ (the kernel sums bk-wide blocks),
+# emulation rather than a single bf16 pass.  Split each f32 operand into
+# three bf16 pieces, a = a1 + a2 + a3 with |a2| < 2**-8 |a| and
+# |a3| < 2**-16 |a|.  The cheapest such scheme (3 passes) keeps a1*b1,
+# a1*b2 and a2*b1 and drops a2*b2, a1*b3 and a3*b1: at most 3 * 2**-16 of
+# each product, so the two sides differ by at most 6 * 2**-16 < 2**-13
+# per product.  Rounding errors of random data carry random signs, so
+# that bound holds for the dot's result in relative norm too.  The f32
+# accumulation orders also differ (the kernel sums bk-wide blocks),
 # adding ~log2(K) * 2**-24 — negligible beside it.  So one chained dot
-# moves a result by at most ~2**-15 in relative norm, and a chain of D
-# dots (every realized layer rescales by 1/sqrt(C), so magnitudes stay
-# ~1) by at most D * 2**-15.
-PER_DOT_TOL = 2.0 ** -15
+# moves a result by at most 2**-13 in relative norm, and a chain of D dots
+# (every realized layer rescales by 1/sqrt(C), so magnitudes stay ~1) by
+# at most D * 2**-13.  A single bf16 pass (2**-8 per operand) fails it.
+PER_DOT_TOL = 2.0 ** -13
 
 
 def _say(msg: str) -> None:
@@ -214,7 +218,7 @@ def realize_phase(ckpt: Path, workloads, n_cores: int, seed: int):
     _check(set(got) == set(want) and worst <= tol,
            f"{len(want)} realized output cubes: Pallas vs jnp twin worst "
            f"relative error {worst:.3e} <= {tol:.3e} "
-           f"({len(g.layers)} chained layers x 2**-15)")
+           f"({len(g.layers)} chained layers x 2**-13)")
     span = {d.id for sp in prog.stages for d in sp.mesh.devices.flat}
     return compile_s, rep, span
 
