@@ -1,0 +1,10 @@
+"""Share of the traced realize window (%) in which no operation ran on the
+device: 1 - union of device-op intervals / window."""
+
+
+def read(run):
+    s = run.trace_summary
+    if not s or s["idle_share"] is None or \
+            run.cell.mix["kind"] != "realize_passes":
+        return None
+    return 100.0 * s["idle_share"]
