@@ -27,6 +27,7 @@ from __future__ import annotations
 
 from collections import OrderedDict
 from dataclasses import dataclass, field
+from functools import lru_cache
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -66,10 +67,11 @@ CACHE_STATS: Dict[str, int] = {
 }
 _obs_metrics.register_collector(lambda: dict(CACHE_STATS))
 
-# Fused-pass activity, process-wide like CACHE_STATS: jitted calls made and
+# Fused-pass activity, process-wide like CACHE_STATS: jitted calls made,
 # the platforms their results lived on — how a caller checks that the
-# scoring pass really left the host.
-FUSED_STATS: Dict[str, Any] = {"calls": 0, "platforms": set()}
+# scoring pass really left the host — and traces of the shared program
+# (``fused``), i.e. misses of the process's program cache.
+FUSED_STATS: Dict[str, Any] = {"calls": 0, "traces": 0, "platforms": set()}
 
 
 @dataclass
@@ -99,89 +101,126 @@ class EvalResult:
         return (self.energy_j ** beta) * (self.delay_s ** gamma)
 
 
-def _build_fused_fn(layout: Sequence[Tuple[int, int]], buf_len: int,
-                    noc_mask: np.ndarray, d2d_mask: np.ndarray,
-                    has_d2d: bool, arch: ArchConfig):
-    """Compile the fused construct->replay->eval pass for one evaluator.
+def fused(B, buf_len, spans, has_d2d, idx, vals, n_passes, depth,
+          weight_totals, noc_m, d2d_m, consts):
+    """The fused construct->replay->eval program, shared by every evaluator.
 
-    Returns a jitted function ``(B, idx, vals, n_passes, depth,
-    weight_totals) -> (delay, energy, stage, overflow, bottleneck_idx,
-    energy_parts)`` where ``idx``/``vals`` are the batch's concatenated
-    int32/float32 contribution streams (pad entries aimed at the
-    ``B * buf_len`` dump cell).  The segment-sum replay and the whole
-    delay/energy pipeline run inside ONE jit, so an accelerator sees a
-    single fused kernel instead of a bincount plus a dozen NumPy ops.
+    Static: the batch bucket ``B``, the row length ``buf_len``, the target
+    ``spans`` of a row and ``has_d2d`` — all fixed by the core grid and
+    the batch size.  Everything a candidate owns is an operand: the NoC /
+    D2D edge masks and ``consts``, the float32 vector of its arch and
+    technology scalars (``_fused_consts``).  So one jitted program serves
+    every candidate of a core grid, per ``(B, buf_len, padded length)``.
+
+    ``idx``/``vals`` are the batch's concatenated int32/float32
+    contribution streams (pad entries aimed at the ``B * buf_len`` dump
+    cell).  The segment-sum replay and the whole delay/energy pipeline run
+    inside ONE jit, so an accelerator sees a single fused kernel instead of
+    a bincount plus a dozen NumPy ops.  This body runs only when JAX traces
+    it: ``FUSED_STATS["traces"]`` counts the misses of the process's
+    program cache.
 
     Float32 + unordered segment reduction make this parity-grade
     (~1e-4 relative), never bit-identical — the exact NumPy engine stays
     the default and re-scores every winner (DESIGN.md).
     """
-    from functools import partial
-
     import jax
     import jax.numpy as jnp
 
+    FUSED_STATS["traces"] += 1
+    (noc_bw, d2d_bw, dram_bw, dram_port_bw, glb_cap, n_cores,
+     e_mac_unit, e_glb_unit, e_hop_unit, e_d2d_unit, e_dram_unit) = consts
+    buf = jax.ops.segment_sum(vals, idx, num_segments=B * buf_len + 1)
+    buf = buf[:-1].reshape(B, buf_len)
+
+    def tgt(t):
+        lo, hi = spans[t]
+        return buf[:, lo:hi]
+
+    core_time = tgt(T_CORE_TIME)
+    glb_rw = tgt(T_GLB_RW)
+    edge_tot = tgt(T_EDGE) + tgt(T_EDGE_AM)
+    edge_noc = edge_tot * noc_m
+    edge_d2d = edge_tot * d2d_m
+    t_noc = edge_noc.max(axis=1, initial=0.0) / noc_bw
+    if has_d2d:
+        t_d2d = edge_d2d.max(axis=1, initial=0.0) / d2d_bw
+    else:
+        t_d2d = jnp.zeros_like(t_noc)
+    dram_tot = tgt(T_DRAM) + tgt(T_DRAM_AM)
+    t_dram = dram_tot.max(axis=1, initial=0.0) / dram_port_bw
+    t_comp = core_time.max(axis=1, initial=0.0)
+    times = jnp.stack([t_comp, t_noc, t_d2d, t_dram])
+    stage = jnp.maximum(times.max(axis=0), 1e-12)
+    b_idx = jnp.argmax(times, axis=0)
+
+    over = jnp.maximum(tgt(T_GLB) - glb_cap, 0.0)
+    overflow = over.sum(axis=1)
+    spill = overflow * 2.0
+    stage = stage * (1.0 + overflow / (glb_cap * n_cores))
+    stage = stage + spill / dram_bw
+    np_f = n_passes.astype(jnp.float32)
+    delay = stage * (np_f + depth.astype(jnp.float32) - 1.0)
+
+    noc_bytes = edge_noc.sum(axis=1) * np_f
+    d2d_bytes = edge_d2d.sum(axis=1) * np_f
+    dram_b = tgt(T_DRAM).sum(axis=1) * np_f + weight_totals \
+        + spill * np_f
+    macs = tgt(T_CORE_MACS).sum(axis=1) * np_f
+    e_mac = macs * e_mac_unit
+    e_glb = (glb_rw[:, 0] + glb_rw[:, 1] + tgt(T_CORE_IN).sum(axis=1)) \
+        * np_f * e_glb_unit
+    e_noc = (noc_bytes + d2d_bytes) * e_hop_unit
+    e_d2d = d2d_bytes * e_d2d_unit
+    e_dram = dram_b * e_dram_unit
+    energy = e_mac + e_glb + e_noc + e_d2d + e_dram
+    return (delay, energy, stage, overflow, b_idx,
+            jnp.stack([e_mac, e_glb, e_noc, e_d2d, e_dram]))
+
+
+@lru_cache(maxsize=None)
+def _fused_program():
+    """The process-wide jitted :func:`fused`, built on first use so that
+    importing this module never pulls in jax."""
+    import jax
+    return jax.jit(fused, static_argnums=(0, 1, 2, 3))
+
+
+def _fused_consts(arch: ArchConfig) -> np.ndarray:
+    """The candidate's scalars, in the order :func:`fused` unpacks them."""
     tech = arch.tech
-    noc_m = jnp.asarray(noc_mask, dtype=jnp.float32)
-    d2d_m = jnp.asarray(d2d_mask, dtype=jnp.float32)
-    noc_bw = arch.noc_bw * 1e9
-    d2d_bw = arch.d2d_bw * 1e9
-    dram_bw = arch.dram_bw * 1e9
-    dram_port_bw = arch.dram_bw / arch.n_dram * 1e9
-    glb_cap = float(arch.core_glb_bytes)
-    n_cores = arch.n_cores
+    return np.array([arch.noc_bw * 1e9, arch.d2d_bw * 1e9,
+                     arch.dram_bw * 1e9, arch.dram_bw / arch.n_dram * 1e9,
+                     arch.core_glb_bytes, arch.n_cores,
+                     tech.e_mac, tech.e_glb_byte, tech.e_noc_hop_byte,
+                     tech.e_d2d_byte, tech.e_dram_byte], dtype=np.float32)
+
+
+def _build_fused_fn(layout: Sequence[Tuple[int, int]], buf_len: int,
+                    noc_mask: np.ndarray, d2d_mask: np.ndarray,
+                    has_d2d: bool, arch: ArchConfig):
+    """Bind one evaluator's operands to the shared :func:`fused` program.
+
+    The masks and arch constants go to the device once, here.  Returns
+    ``(B, idx, vals, n_passes, depth, weight_totals) -> (delay, energy,
+    stage, overflow, bottleneck_idx, energy_parts)``, device arrays of
+    ``B`` rows; pad entries of ``idx`` aim at the ``B * buf_len`` dump
+    cell.
+    """
+    import jax
+
+    operands = jax.device_put((np.asarray(noc_mask, dtype=np.float32),
+                               np.asarray(d2d_mask, dtype=np.float32),
+                               _fused_consts(arch)))
     spans = tuple((int(lo), int(hi)) for lo, hi in layout)
+    static = (int(buf_len), spans, bool(has_d2d))
+    program = _fused_program()
 
-    @partial(jax.jit, static_argnums=(0,))
-    def fused(B, idx, vals, n_passes, depth, weight_totals):
-        buf = jax.ops.segment_sum(vals, idx, num_segments=B * buf_len + 1)
-        buf = buf[:-1].reshape(B, buf_len)
+    def call(B, idx, vals, n_passes, depth, weight_totals):
+        return program(B, *static, idx, vals, n_passes, depth,
+                       weight_totals, *operands)
 
-        def tgt(t):
-            lo, hi = spans[t]
-            return buf[:, lo:hi]
-
-        core_time = tgt(T_CORE_TIME)
-        glb_rw = tgt(T_GLB_RW)
-        edge_tot = tgt(T_EDGE) + tgt(T_EDGE_AM)
-        edge_noc = edge_tot * noc_m
-        edge_d2d = edge_tot * d2d_m
-        t_noc = edge_noc.max(axis=1, initial=0.0) / noc_bw
-        if has_d2d:
-            t_d2d = edge_d2d.max(axis=1, initial=0.0) / d2d_bw
-        else:
-            t_d2d = jnp.zeros_like(t_noc)
-        dram_tot = tgt(T_DRAM) + tgt(T_DRAM_AM)
-        t_dram = dram_tot.max(axis=1, initial=0.0) / dram_port_bw
-        t_comp = core_time.max(axis=1, initial=0.0)
-        times = jnp.stack([t_comp, t_noc, t_d2d, t_dram])
-        stage = jnp.maximum(times.max(axis=0), 1e-12)
-        b_idx = jnp.argmax(times, axis=0)
-
-        over = jnp.maximum(tgt(T_GLB) - glb_cap, 0.0)
-        overflow = over.sum(axis=1)
-        spill = overflow * 2.0
-        stage = stage * (1.0 + overflow / (glb_cap * n_cores))
-        stage = stage + spill / dram_bw
-        np_f = n_passes.astype(jnp.float32)
-        delay = stage * (np_f + depth.astype(jnp.float32) - 1.0)
-
-        noc_bytes = edge_noc.sum(axis=1) * np_f
-        d2d_bytes = edge_d2d.sum(axis=1) * np_f
-        dram_b = tgt(T_DRAM).sum(axis=1) * np_f + weight_totals \
-            + spill * np_f
-        macs = tgt(T_CORE_MACS).sum(axis=1) * np_f
-        e_mac = macs * tech.e_mac
-        e_glb = (glb_rw[:, 0] + glb_rw[:, 1] + tgt(T_CORE_IN).sum(axis=1)) \
-            * np_f * tech.e_glb_byte
-        e_noc = (noc_bytes + d2d_bytes) * tech.e_noc_hop_byte
-        e_d2d = d2d_bytes * tech.e_d2d_byte
-        e_dram = dram_b * tech.e_dram_byte
-        energy = e_mac + e_glb + e_noc + e_d2d + e_dram
-        return (delay, energy, stage, overflow, b_idx,
-                jnp.stack([e_mac, e_glb, e_noc, e_d2d, e_dram]))
-
-    return fused
+    return call
 
 
 def _pipeline_depth(g: Graph, group: LayerGroup) -> int:
@@ -386,9 +425,11 @@ class Evaluator:
         Construction is the same batched engine the exact path uses
         (``_prefetch_contribs`` + cached ``row_stream`` downcasts); the
         replay and the entire delay/energy pipeline then run as ONE jitted
-        kernel.  Streams are padded to power-of-two lengths (pad entries
-        scatter into a dump cell past the last row) so jit retraces stay
-        rare and shapes stabilize quickly under SA stepping.
+        kernel, the process-wide :func:`fused` program.  Streams are padded
+        to power-of-two lengths (pad entries scatter into a dump cell past
+        the last row) and the batch to a power of two of at least 4 rows
+        (pad rows carry no entries and are dropped on the host), so that
+        every candidate of a core grid shares a handful of programs.
 
         Returns ``(GroupEval, None)`` tuples: the fused path never
         materializes per-row :class:`GroupAnalysis` views.  Results carry
@@ -400,12 +441,13 @@ class Evaluator:
         an = self.analyzer
         an._prefetch_contribs(requests, total_batch)
         B = len(requests)
+        B_pad = 1 << max(2, (B - 1).bit_length())
         buf_len = an._buf_len
         idx_parts: List[np.ndarray] = []
         val_parts: List[np.ndarray] = []
-        wts = np.empty(B, dtype=np.float32)
-        npass = np.empty(B, dtype=np.int32)
-        dep = np.empty(B, dtype=np.int32)
+        wts = np.zeros(B_pad, dtype=np.float32)
+        npass = np.ones(B_pad, dtype=np.int32)
+        dep = np.ones(B_pad, dtype=np.int32)
         for b, (grp, lms) in enumerate(requests):
             i, v, wt = an.row_stream(grp, lms, total_batch)
             idx_parts.append(i + np.int32(b * buf_len) if b else i)
@@ -418,7 +460,7 @@ class Evaluator:
         n = idx.size
         n_pad = 1 << max(4, (max(n, 1) - 1).bit_length())
         if n_pad != n:
-            dump = np.int32(B * buf_len)
+            dump = np.int32(B_pad * buf_len)
             idx = np.concatenate([idx, np.full(n_pad - n, dump, np.int32)])
             vals = np.concatenate([vals, np.zeros(n_pad - n, np.float32)])
         if self._fused_fn is None:
@@ -426,7 +468,7 @@ class Evaluator:
                 an._layout, buf_len, self._not_d2d, self._is_d2d,
                 self._has_d2d, self.arch)
         delay, energy, stage, overflow, b_idx, eparts = \
-            self._fused_fn(B, idx, vals, npass, dep, wts)
+            self._fused_fn(B_pad, idx, vals, npass, dep, wts)
         FUSED_STATS["calls"] += 1
         FUSED_STATS["platforms"].update(d.platform for d in delay.devices())
         delay = np.asarray(delay)

@@ -3,12 +3,15 @@ jax replay backend: parity envelopes across the workload zoo, bad-backend /
 bad-dtype refusal, fused-vs-exact cache separation in CachedEvaluator, and
 the rescore-winners contract of ``SAConfig(backend="jax")``."""
 
+from itertools import combinations
+
 import numpy as np
 import pytest
 
 from repro.core.analyzer import _jax_replay
 from repro.core.encoding import random_lms
-from repro.core.evaluator import CachedEvaluator, Evaluator
+from repro.core.evaluator import (FUSED_STATS, CachedEvaluator, Evaluator,
+                                  _fused_program)
 from repro.core.explore import replica_exchange_sa
 from repro.core.graph_partition import partition_graph
 from repro.core.hw import ArchConfig
@@ -22,10 +25,10 @@ REL_TOL = 1e-4
 ZOO = ("tf-quick", "moe-quick", "mla-quick")
 
 
-def _arch():
-    return ArchConfig(x_cores=4, y_cores=3, xcut=2, ycut=1,
-                      noc_bw=16.0, d2d_bw=8.0, dram_bw=64.0,
-                      glb_kb=512, macs_per_core=256)
+def _arch(**kw):
+    return ArchConfig(**{**dict(x_cores=4, y_cores=3, xcut=2, ycut=1,
+                                noc_bw=16.0, d2d_bw=8.0, dram_bw=64.0,
+                                glb_kb=512, macs_per_core=256), **kw})
 
 
 def _requests(g, arch, seed=0, n=3):
@@ -35,18 +38,7 @@ def _requests(g, arch, seed=0, n=3):
             for grp in groups for _ in range(n)]
 
 
-# ---------------------------------------------------------------------------
-# fused evaluator pass
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("spec", ZOO)
-def test_fused_parity_envelope(spec):
-    arch = _arch()
-    g = make_workload(spec)
-    reqs = _requests(g, arch, seed=1)
-    ev = Evaluator(arch, g)
-    exact = ev.eval_requests_batch(reqs, 8)
-    fused = ev.eval_requests_batch(reqs, 8, backend="jax")
+def _assert_parity(exact, fused):
     assert len(fused) == len(exact)
     for (ge, an), (gf, anf) in zip(exact, fused):
         assert anf is None        # fused rows carry no analyses by contract
@@ -59,6 +51,75 @@ def test_fused_parity_envelope(spec):
         for k in ge.energy_breakdown:
             a, b = ge.energy_breakdown[k], gf.energy_breakdown[k]
             assert abs(a - b) <= REL_TOL * max(abs(a), 1e-12)
+
+
+# ---------------------------------------------------------------------------
+# fused evaluator pass
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("spec", ZOO)
+def test_fused_parity_envelope(spec):
+    arch = _arch()
+    g = make_workload(spec)
+    reqs = _requests(g, arch, seed=1)
+    ev = Evaluator(arch, g)
+    _assert_parity(ev.eval_requests_batch(reqs, 8),
+                   ev.eval_requests_batch(reqs, 8, backend="jax"))
+
+
+@pytest.mark.parametrize("spec", ZOO)
+def test_fused_program_shared_across_candidates(spec):
+    """Two candidates of one core grid, with their own cut, bandwidths and
+    GLB, run one traced program, each with its own constants."""
+    g = make_workload(spec)
+    arch_a = _arch()
+    arch_b = _arch(xcut=1, ycut=3, noc_bw=4.0, d2d_bw=2.0, dram_bw=16.0,
+                   glb_kb=1024)
+    reqs = _requests(g, arch_a, seed=6, n=1)[:4]
+    _fused_program().clear_cache()
+    before = FUSED_STATS["traces"]
+    delays = []
+    for arch in (arch_a, arch_b):
+        ev = Evaluator(arch, g)
+        fused = ev.eval_requests_batch(reqs, 8, backend="jax")
+        _assert_parity(ev.eval_requests_batch(reqs, 8), fused)
+        delays.append([gf.delay_s for gf, _ in fused])
+    assert FUSED_STATS["traces"] == before + 1
+    assert delays[0] != delays[1]
+
+
+def _one_bucket_batches(ev, total_batch):
+    """Batches of 1-4 requests whose streams pad to one length, so that
+    only the batch size tells them apart: mappings on one core a layer
+    (short streams) mixed with mappings over the whole grid (long ones)."""
+    g, arch, an = ev.g, ev.arch, ev.analyzer
+    rng = np.random.default_rng(0)
+    reqs = [(grp, random_lms(grp, g, nc, arch.n_dram, rng))
+            for grp in partition_graph(g, arch, total_batch)
+            for nc in (len(grp.names), arch.n_cores)]
+    an._prefetch_contribs(reqs, total_batch)
+    lens = [an.row_stream(grp, lms, total_batch)[0].size for grp, lms in reqs]
+    cap = 1 << (max(lens) - 1).bit_length()
+    order = sorted(range(len(reqs)), key=lens.__getitem__)
+    return {k: [reqs[i] for i in next(
+                c for c in combinations(order, k)
+                if cap // 2 < sum(lens[i] for i in c) <= cap)]
+            for k in (1, 2, 3, 4)}
+
+
+@pytest.mark.parametrize("first", [1, 2, 3, 4])
+def test_fused_batch_sizes_share_one_program(first):
+    """Batches of 1-4 requests pad to one batch bucket: the size met first
+    traces the program, the others reuse it, and each gets its own rows."""
+    arch = _arch()
+    ev = Evaluator(arch, make_workload("tf-quick"))
+    batches = _one_bucket_batches(ev, 8)
+    _fused_program().clear_cache()
+    before = FUSED_STATS["traces"]
+    for k in sorted(batches, key=lambda k: k != first):
+        fused = ev.eval_requests_batch(batches[k], 8, backend="jax")
+        assert FUSED_STATS["traces"] == before + 1
+        _assert_parity(ev.eval_requests_batch(batches[k], 8), fused)
 
 
 def test_fused_empty_requests():
@@ -175,7 +236,6 @@ def test_engine_refuses_workers_with_fused_backend():
 
 
 def test_fused_stats_count_calls_and_platform():
-    from repro.core.evaluator import FUSED_STATS
     arch = _arch()
     g = make_workload("tf-quick")
     before = FUSED_STATS["calls"]

@@ -20,7 +20,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.bridge import lms_to_plan
 from repro.core.dse import grid_candidates
-from repro.core.evaluator import Evaluator, _build_fused_fn
+from repro.core.evaluator import Evaluator, _fused_consts, _fused_program
 from repro.core.graph_partition import partition_graph
 from repro.core.tangram import tangram_map
 from repro.core.workloads import transformer
@@ -100,13 +100,15 @@ def test_fused_scorer_compiles(one_chip, table1_graph):
                            d2d_ratio=(0.5,), glb_options=(2048,))[0]
     ev = Evaluator(arch, table1_graph)
     an = ev.analyzer
-    fused = _build_fused_fn(an._layout, an._buf_len, ev._not_d2d,
-                            ev._is_d2d, ev._has_d2d, arch)
-    B, n = 4, 1 << 20                         # 4 lockstep chains
-    compiled = fused.lower(
-        B, _struct((n,), one_chip, jnp.int32), _struct((n,), one_chip),
+    spans = tuple(an._layout)
+    B, n = 4, 1 << 20              # the batch bucket of 4 lockstep chains
+    ne, nk = ev._is_d2d.size, _fused_consts(arch).size
+    compiled = _fused_program().lower(
+        B, an._buf_len, spans, ev._has_d2d,
+        _struct((n,), one_chip, jnp.int32), _struct((n,), one_chip),
         _struct((B,), one_chip, jnp.int32), _struct((B,), one_chip, jnp.int32),
-        _struct((B,), one_chip)).compile()
+        _struct((B,), one_chip), _struct((ne,), one_chip),
+        _struct((ne,), one_chip), _struct((nk,), one_chip)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes > 0
 
 
