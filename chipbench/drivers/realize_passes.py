@@ -13,8 +13,11 @@ and stops there.
 Check: a few passes drawn from the seed keep their exported cubes; once
 the window has closed and the memory peak is read, the program is freed
 and ``reference/realized.py`` recomputes those passes from the same seeds
-at ``highest``.  ``cube_rel_err`` is the worst relative error in norm over
-every exported cube of those passes.
+at ``highest``, over the layers that the configuration's reference module
+gives (``realized_layers``), stage by stage as the plan runs them: the
+reference is handed the plan's stages as lists of layer names in
+execution order, and nothing else of the program.  ``cube_rel_err`` is the
+worst relative error in norm over every cube that those passes export.
 """
 
 from __future__ import annotations
@@ -32,6 +35,8 @@ from chipbench.reference import realized as ref
 
 # limit of cube_rel_err; PERF.md gives the readings it was set from
 CUBE_REL_ERR_LIMIT = 5e-6
+# what the configuration's reference module has to give
+REFERENCE_NEEDS = ("realized_layers",)
 
 
 def pass_seeds(seed: int):
@@ -71,13 +76,13 @@ def run(r, t_start: float) -> None:
         r.obs["setup_compile_s"] = sum(sp.compile_s for sp in prog.stages)
         prog.execute(seed=0)                       # warm pass
 
-    order = [sp.stage.layers for sp in prog.stages]
-    if any(len(names) != 1 for names in order):
-        raise ValueError("the reference covers one-layer stages only")
-    layers = {l.name: l for l in ref.transformer_layers(
-        int(cfg["n_layers"]), int(cfg["d_model"]), int(cfg["d_ff"]),
-        int(cfg["seq"]))}
-    in_order = [layers[names[0]] for names in order]
+    # the plan's stages cross to the reference as layer names alone
+    stages = [list(sp.stage.layers) for sp in prog.stages]
+    layers = {l.name: l for l in r.cell.reference.realized_layers(cfg)}
+    unknown = sorted({n for st in stages for n in st} - set(layers))
+    if unknown:
+        r.fail(f"the plan has layers its reference lacks: {unknown[:5]}")
+    in_order = [layers[n] for st in stages for n in st]
     bu = prog.batch_unit
     r.obs["batch_unit"] = bu
     r.obs["pass_macs"] = ref.pass_macs(in_order, bu)
@@ -128,10 +133,10 @@ def run(r, t_start: float) -> None:
     gc.collect()
     worst, failed = 0.0, 0
     for s, outs in got.items():
-        want = ref.forward(in_order, bu, s,
+        want = ref.forward(layers, stages, bu, s,
                            precision="high" if r.control else "highest")
         if r.control:                  # the control in the program's place
-            outs, want = want, ref.forward(in_order, bu, s)
+            outs, want = want, ref.forward(layers, stages, bu, s)
         errs = [ref.rel_err(outs[n], want[n]) if n in outs else float("inf")
                 for n in want]
         failed += max(errs) > CUBE_REL_ERR_LIMIT

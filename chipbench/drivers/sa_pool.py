@@ -23,9 +23,12 @@ process holds a window task.  The window's programs are compiled before
 it: a checkout's first run starts a child process that runs every task
 once (``fill``), fills the persistent compile cache and exits before this
 process touches the chip, and leaves a marker (``filled.json``); a later
-run finds the marker and starts none.  Each window task's evaluator still
-traces its own fused closure and loads it from that cache: that is the
-sweep's own cost and falls inside the window.
+run finds the marker and starts none.  Every evaluator binds its
+candidate's masks and constants, as operands, to the one fused program
+the process shares (``_build_fused_fn``); a window task asks JAX for an
+executable only for a (batch bucket, ``buf_len``, padded length) that no
+earlier task of the process met, and then loads it from that cache: that
+is the sweep's own cost and falls inside the window.
 
 The window runs tasks until ``--seconds`` has passed; the task in flight
 finishes and counts.  A traced run traces the first ``trace.tasks`` tasks
@@ -41,8 +44,10 @@ reference (``reference/costmodel.py``):
 
 The SA steps and fused calls are seen by wrapping three bindings of the
 program (``explore.step_chains_lockstep``, ``Evaluator._eval_requests_fused``
-and ``evaluator._build_fused_fn``); a window in which any of them saw
-nothing fails the run rather than report without them.
+and ``evaluator._build_fused_fn``, whose returned call runs the shared
+program); a window in which any of them saw nothing fails the run rather
+than report without them.  The reference scores on the graph that the
+configuration's reference module builds (``build(config)``).
 """
 
 from __future__ import annotations
@@ -60,7 +65,6 @@ from typing import Any, Dict, List, Sequence, Tuple
 import numpy as np
 
 from chipbench import trace as tr
-from chipbench.reference import graphs
 from chipbench.reference.costmodel import CostModel
 
 # limits; PERF.md gives the readings each was set from
@@ -339,7 +343,7 @@ def _check(r, cfg, mix, tasks, hooks) -> None:
     import ml_dtypes
 
     wl, batch = cfg["workload"]["name"], int(cfg["batch"])
-    graph = graphs.build(cfg)
+    graph = r.cell.reference.build(cfg)
     models: Dict[Tuple[int, Any], CostModel] = {}
 
     def ref(k: int, dt=np.float64) -> CostModel:

@@ -2,8 +2,10 @@
 the ``/jax/core/compile/backend_compile_duration`` events a
 ``jax.monitoring`` listener counts, which JAX records for every compile
 request, whether the persistent cache then serves it (``cache_loads``) or
-the backend compiles it.  Each new evaluator jits its own fused closure,
-so a sweep pays at least one per candidate and (B, padded length) pair."""
+the backend compiles it.  Every evaluator binds its candidate to the one
+fused program the process shares, so a sweep pays one only for a (batch
+bucket, ``buf_len``, padded length) that no earlier call of the process
+met, whatever the candidate."""
 
 
 def read(run):

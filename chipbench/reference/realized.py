@@ -1,9 +1,12 @@
-"""Plain reference of one realized pass of the Table-I transformer.
+"""Plain reference of one realized pass of a plan.
 
 What the realized program computes, layer by layer, in straightforward
 ``jax.numpy`` float32 with every matmul at an explicit precision, built
 from the configuration's sizes alone: it imports nothing of the program.
-The layer list is the paper's transformer encoder block (Vaswani et al.):
+A configuration's reference module gives the layers (``realized_layers``:
+:class:`RefLayer` objects, each ``fc``, ``qk``, ``av`` or ``add``); the
+caller gives the plan's stages as lists of layer names in execution order.
+For the paper's transformer encoder block (Vaswani et al.):
 
     q, k, v = fc(x); qk = softmax(q k^T / sqrt(d)); av = qk v / sqrt(seq)
     o = fc(av); add1 = o + x; ff1 = fc(add1); ff2 = fc(ff1); add2 = ff2 + add1
@@ -13,12 +16,16 @@ layer's output is a cube ``(batch unit, H, 1, K)``.  Where an operand
 has another size than the contraction wants (the weight side of ``qk`` and
 ``av`` is the first ``C x K`` elements of the producer's cube), it is
 tiled or truncated in row-major order, as the realization's operand
-bridge does.
+bridge does.  This arithmetic is the layers' own: it does not change with
+how a plan groups them into stages.
 
 Inputs and weights come from a seed, drawn in the order the program's
-stages consume them: ``numpy.random.default_rng(seed)``, then for each
-layer in execution order a standard-normal source ifmap if the layer has no
-producer, then its weight if it has one (float64 draws cast to float32).
+stages consume them: ``numpy.random.default_rng(seed)``, then stage by
+stage, first a standard-normal source ifmap for each of the stage's layers
+that has no producer, in stage order, then a weight for each of its ``fc``
+layers, in stage order (float64 draws cast to float32).  With one layer a
+stage this is that layer's ifmap, then its weight.  A pass exports the
+cubes that a later stage reads, and those that nothing reads.
 """
 
 from __future__ import annotations
@@ -39,31 +46,6 @@ class RefLayer:
     preds: Tuple[str, ...]
 
 
-def transformer_layers(n_layers: int, d_model: int, d_ff: int, seq: int
-                       ) -> List[RefLayer]:
-    out: List[RefLayer] = []
-    prev: Tuple[str, ...] = ()
-    for i in range(n_layers):
-        t = f"l{i}"
-        out += [RefLayer(f"{t}_q", "fc", seq, d_model, d_model, prev),
-                RefLayer(f"{t}_k", "fc", seq, d_model, d_model, prev),
-                RefLayer(f"{t}_v", "fc", seq, d_model, d_model, prev),
-                RefLayer(f"{t}_qk", "qk", seq, d_model, seq,
-                         (f"{t}_q", f"{t}_k")),
-                RefLayer(f"{t}_av", "av", seq, seq, d_model,
-                         (f"{t}_qk", f"{t}_v")),
-                RefLayer(f"{t}_o", "fc", seq, d_model, d_model, (f"{t}_av",)),
-                RefLayer(f"{t}_add1", "add", seq, 0, d_model,
-                         (f"{t}_o",) + prev),
-                RefLayer(f"{t}_ff1", "fc", seq, d_model, d_ff,
-                         (f"{t}_add1",)),
-                RefLayer(f"{t}_ff2", "fc", seq, d_ff, d_model, (f"{t}_ff1",)),
-                RefLayer(f"{t}_add2", "add", seq, 0, d_model,
-                         (f"{t}_ff2", f"{t}_add1"))]
-        prev = (f"{t}_add2",)
-    return out
-
-
 def gemm_shapes(layers: Sequence[RefLayer], bu: int
                 ) -> List[Tuple[int, int, int]]:
     """(M, K, N) of every GEMM of one pass: one per fc, qk and av layer."""
@@ -79,20 +61,41 @@ def pass_macs(layers: Sequence[RefLayer], bu: int) -> int:
     return macs * bu
 
 
-def draws(layers_in_order: Sequence[RefLayer], bu: int, seed: int
-          ) -> Dict[str, np.ndarray]:
-    """Source ifmaps (key ``<layer>:x``) and weights (``<layer>:w``)."""
+def draws(layers: Dict[str, RefLayer], stages: Sequence[Sequence[str]],
+          bu: int, seed: int) -> Dict[str, np.ndarray]:
+    """Source ifmaps (key ``<layer>:x``) and weights (``<layer>:w``),
+    drawn stage by stage."""
     rng = np.random.default_rng(seed)
     out: Dict[str, np.ndarray] = {}
-    for l in layers_in_order:
-        if not l.preds:
-            cin = max(l.C, 1) if l.kind != "add" else l.K
-            out[f"{l.name}:x"] = rng.normal(
-                size=(bu, l.H, 1, cin)).astype(np.float32)
-        if l.kind == "fc":
-            out[f"{l.name}:w"] = rng.normal(size=(l.C, l.K)).astype(
-                np.float32)
+    for stage in stages:
+        for l in (layers[n] for n in stage):
+            if not l.preds:
+                cin = max(l.C, 1) if l.kind != "add" else l.K
+                out[f"{l.name}:x"] = rng.normal(
+                    size=(bu, l.H, 1, cin)).astype(np.float32)
+        for l in (layers[n] for n in stage):
+            if l.kind == "fc":
+                out[f"{l.name}:w"] = rng.normal(size=(l.C, l.K)).astype(
+                    np.float32)
     return out
+
+
+def _consumers(layers: Dict[str, RefLayer]) -> Dict[str, List[str]]:
+    succs: Dict[str, List[str]] = {n: [] for n in layers}
+    for l in layers.values():
+        for p in l.preds:
+            succs[p].append(l.name)
+    return succs
+
+
+def exported(layers: Dict[str, RefLayer], stages: Sequence[Sequence[str]]
+             ) -> List[str]:
+    """The cubes a pass exports: read by a later stage, or by nothing."""
+    stage_of = {n: i for i, stage in enumerate(stages) for n in stage}
+    succs = _consumers(layers)
+    return [n for stage in stages for n in stage
+            if not succs[n] or any(stage_of[s] > stage_of[n]
+                                   for s in succs[n])]
 
 
 def _fit(x, shape):
@@ -132,19 +135,19 @@ def _dot_bf16x3(a, b):
     return dot(a1, b1) + dot(a1, b2) + dot(a2, b1)
 
 
-def forward(layers_in_order: Sequence[RefLayer], bu: int, seed: int,
-            precision: str = "highest") -> Dict[str, np.ndarray]:
-    """Every layer's output cube of one pass, as host float32 arrays.
+def forward(layers: Dict[str, RefLayer], stages: Sequence[Sequence[str]],
+            bu: int, seed: int, precision: str = "highest"
+            ) -> Dict[str, np.ndarray]:
+    """Every exported cube of one pass, as host float32 arrays.
     ``precision="high"`` is the control: every matmul at three bfloat16
     passes instead of float32."""
     import jax
     import jax.numpy as jnp
 
-    w = draws(layers_in_order, bu, seed)
+    w = draws(layers, stages, bu, seed)
     mm = jax.jit({"highest": _dot_highest, "high": _dot_bf16x3}[precision])
     vals: Dict[str, jax.Array] = {}
-    out: Dict[str, np.ndarray] = {}
-    for l in layers_in_order:
+    for l in (layers[n] for stage in stages for n in stage):
         shape = (bu, l.H, 1, l.K)
         if l.kind == "add":
             y = sum(_fit(vals[p], shape) for p in l.preds)
@@ -158,8 +161,7 @@ def forward(layers_in_order: Sequence[RefLayer], bu: int, seed: int,
             if l.kind == "qk":
                 y = jax.nn.softmax(y, axis=-1)
         vals[l.name] = y
-        out[l.name] = np.asarray(y)
-    return out
+    return {n: np.asarray(vals[n]) for n in exported(layers, stages)}
 
 
 def rel_err(got: np.ndarray, want: np.ndarray) -> float:

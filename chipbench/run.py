@@ -7,15 +7,19 @@ Run from the root of a checkout.  Everything a cell needs is found by name:
 
 * ``BENCHMARK.json`` names the cell's configuration and traffic mix;
 * ``chipbench/configs/<config>.json`` holds the configuration's sizes; its
-  ``workload`` names the program's workload kind (and model) and which of
-  those sizes make up the workload spec the program is given;
+  ``workload`` names the program's workload kind (and model), which of
+  those sizes make up the workload spec the program is given, and the
+  plain reference ``chipbench/reference/models/<reference>.py`` that the
+  checks compare against (``build(config)``, the layer graph; a realize
+  cell's also ``realized_layers(config)``);
 * ``chipbench/traffic/<mix>.json`` holds the mix's parameters, and its
   ``kind`` names the driver ``chipbench/drivers/<kind>.py`` that builds the
   system under test, warms it, runs the measured window and checks its
-  answers against the plain references in ``chipbench/reference/`` (a
-  driver may also have a ``prepare`` step, run before this process touches
-  JAX, that starts the same command with ``--fill 1`` in a child process
-  to fill the checkout's compile cache);
+  answers against the configuration's plain reference (a driver names
+  the functions it needs of it in ``REFERENCE_NEEDS``, and may also have a
+  ``prepare`` step, run before this process touches JAX, that starts the
+  same command with ``--fill 1`` in a child process to fill the
+  checkout's compile cache);
 * each metric is a reader ``chipbench/metrics/<name>.py`` whose
   ``read(run)`` returns a number, or ``None`` where it finds nothing.
 
@@ -46,6 +50,8 @@ from typing import Any, Dict, List, Optional
 
 BENCH_DIR = Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
+if str(ROOT) not in sys.path:     # drivers, metrics and references import
+    sys.path.insert(0, str(ROOT))  # chipbench's shared modules
 # libtpu would otherwise log to the fixed /tmp/tpu_logs
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -70,6 +76,7 @@ def load_module(path: Path):
     spec = importlib.util.spec_from_file_location(
         f"chipbench_{path.parent.name}_{path.stem}".replace("-", "_"), path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod       # where a dataclass looks its module up
     spec.loader.exec_module(mod)
     return mod
 
@@ -78,6 +85,27 @@ def load_json(path: Path) -> Dict[str, Any]:
     if not path.is_file():
         raise BenchError(f"no such file: {path}")
     return json.loads(path.read_text())
+
+
+def require(module, needs, what: str) -> None:
+    """Refuse ``module`` unless it has every function in ``needs``."""
+    missing = [f for f in needs if not callable(getattr(module, f, None))]
+    if missing:
+        raise BenchError(f"{what} has no {', '.join(missing)}")
+
+
+def load_reference(config: Dict[str, Any], root: Path = ROOT):
+    """The plain reference that the configuration's ``workload.reference``
+    names: ``chipbench/reference/models/<name>.py``, which builds the
+    configuration's layer graph (``build``)."""
+    name = str(config["workload"].get("reference", ""))
+    if not name or name.startswith(".") or Path(name).name != name:
+        raise BenchError(f"configuration {config.get('name')!r} names no "
+                         f"reference module: {name!r}")
+    path = root / "chipbench" / "reference" / "models" / f"{name}.py"
+    module = load_module(path)
+    require(module, ("build",), f"reference module {path}")
+    return module
 
 
 def workload_spec(config: Dict[str, Any]) -> str:
@@ -99,6 +127,7 @@ class Cell:
     mix: Dict[str, Any]
     end_to_end: List[Dict[str, Any]]
     per_layer: List[Dict[str, Any]]
+    reference: Any = None              # the configuration's reference module
 
     @property
     def spec(self) -> str:
@@ -123,7 +152,8 @@ def find_cell(name: str, root: Path = ROOT) -> Cell:
                 end_to_end=[m for m in bench["end_to_end"]
                             if _applies(m, name)],
                 per_layer=[m for m in bench["per_layer"]
-                           if _applies(m, name)])
+                           if _applies(m, name)],
+                reference=load_reference(cfg, root))
 
 
 def peaks_for(device_kind: str, root: Path = ROOT) -> Dict[str, float]:
@@ -230,13 +260,15 @@ def execute(cell_name: str, seed: int, seconds: float, trace: bool,
     if not (src / "repro").is_dir():
         raise BenchError(f"no repro package under {src}: the system under "
                          f"test is missing from this checkout")
-    for path in (str(src), str(ROOT)):
-        if path not in sys.path:
-            sys.path.insert(0, path)
+    if str(src) not in sys.path:
+        sys.path.insert(0, str(src))
     work_dir = root / ".chipbench" / cell.name
     work_dir.mkdir(parents=True, exist_ok=True)
     driver = load_module(root / "chipbench" / "drivers"
                          / f"{cell.mix['kind']}.py")
+    require(cell.reference, getattr(driver, "REFERENCE_NEEDS", ()),
+            f"the reference of {cell.name}, which a {cell.mix['kind']} "
+            f"mix needs,")
     if not fill and hasattr(driver, "prepare"):
         driver.prepare(cell, root, work_dir,
                        fill_cmd or fill_command(cell_name), stop)

@@ -1,5 +1,6 @@
 """Run one benchmark cell on the CPU, with an optional fault planted in the
-program underneath the harness; prints the result line.
+program underneath the harness (or, for ``reference-*`` faults, in the
+plain reference); prints the result line.
 
     python drive.py <root> <cell> <fault|-> <control 0|1> <trace 0|1> <seconds> <seed> [fill]
 
@@ -91,6 +92,15 @@ def plant(fault: str) -> None:
                 return res
             return g
         _wrap(RealizedProgram, "execute", make)
+    elif fault == "reference-draws-by-layer":    # the reference's inputs
+        from chipbench.reference import realized  # drawn layer by layer
+
+        def make(f):
+            def g(layers, stages, bu, seed):
+                return f(layers, [[n] for st in stages for n in st], bu,
+                         seed)
+            return g
+        _wrap(realized, "draws", make)
     elif fault != "-":
         raise SystemExit(f"unknown fault {fault!r}")
 

@@ -1,6 +1,7 @@
 """Work counts and references kept with the benchmark, checked by hand and
 against the program."""
 
+import json
 import sys
 from pathlib import Path
 
@@ -12,11 +13,28 @@ sys.path.insert(0, str(ROOT))
 
 from chipbench import run as harness  # noqa: E402
 from chipbench.drivers import sa_pool  # noqa: E402
-from chipbench.reference import graphs, realized  # noqa: E402
+from chipbench.reference import realized  # noqa: E402
 from chipbench.reference.costmodel import CostModel  # noqa: E402
 
 PEAKS = {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9}
-TF = realized.transformer_layers(6, 512, 2048, 512)
+BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
+CONFIGS = sorted(c["name"] for c in BENCH["configs"])
+
+
+def _config(name):
+    (entry,) = [c for c in BENCH["configs"] if c["name"] == name]
+    return json.loads((ROOT / entry["file"]).read_text())
+
+
+def _small(name):
+    """The configuration at the sizes its reference module's ``SMALL``
+    gives for CPU checks."""
+    cfg = _config(name)
+    return dict(cfg, **harness.load_reference(cfg).SMALL)
+
+
+TF = harness.load_reference(_config("table1-tf")).realized_layers(
+    _config("table1-tf"))
 
 
 def _metric(name):
@@ -58,25 +76,11 @@ def test_tiled_matmul_flops_and_bytes():
     assert m.bound((8192, 4096, 8192), PEAKS) == "compute"
 
 
-def _config(name, **sizes):
-    import json
-    cfg = json.loads((ROOT / "chipbench" / "configs"
-                      / f"{name}.json").read_text())
-    return dict(cfg, **sizes)
-
-
-SMALL = {
-    "dense": _config("table1-tf", n_layers=2, d_model=128, d_ff=256, seq=64,
-                     batch=8),
-    "routed": _config("phi35-moe", num_hidden_layers=1, seq=128, batch=8),
-}
-
-
 def _point(arch):
     return {k: getattr(arch, k) for k in sa_pool.ARCH_FIELDS}
 
 
-@pytest.mark.parametrize("which", sorted(SMALL))
+@pytest.mark.parametrize("which", CONFIGS)
 def test_cost_model_reference_equals_the_exact_engine(which):
     """The copied reference, on the graph it builds from the configuration,
     scores mappings bit for bit like the program's exact engine
@@ -89,32 +93,33 @@ def test_cost_model_reference_equals_the_exact_engine(which):
     from repro.core.sa import SAConfig
     from repro.core.workloads import make_workload
 
-    cfg = SMALL[which]
+    cfg = _small(which)
+    batch = int(cfg["batch"])
     g = make_workload(harness.workload_spec(cfg))
-    graph = graphs.build(cfg)
+    graph = harness.load_reference(cfg).build(cfg)
     archs = grid_candidates(72.0, mac_options=(512, 1024, 2048),
                             cut_options=(1, 3), dram_per_tops=(2.0,),
                             noc_options=(32,), d2d_ratio=(0.5,),
                             glb_options=(1024,))
-    dcfg = DSEConfig(batch=8, keep_mappings=True,
+    dcfg = DSEConfig(batch=batch, keep_mappings=True,
                      sa=SAConfig(iters=12, seed=5, n_chains=4))
     for p in run_dse(archs, {"W": g}, dcfg, n_workers=1):
         mapping = p.mappings["W"]
         ref = CostModel(_point(p.arch), cfg["tech"], graph)
         ev = Evaluator(p.arch, g)
-        exact = ev.evaluate(mapping, 8)
-        assert ref.mapping(mapping, 8) == (exact.energy_j, exact.delay_s)
+        exact = ev.evaluate(mapping, batch)
+        assert ref.mapping(mapping, batch) == (exact.energy_j, exact.delay_s)
         for grp, lms in mapping:
-            ge, _ = ev.eval_group(grp, lms, 8)
-            r = ref.group(grp, lms, 8)
+            ge, _ = ev.eval_group(grp, lms, batch)
+            r = ref.group(grp, lms, batch)
             assert (r.delay_s, r.energy_j) == (ge.delay_s, ge.energy_j)
         for dt in (np.float32, ml_dtypes.bfloat16):
             assert CostModel(_point(p.arch), cfg["tech"], graph,
-                             dt).mapping(mapping, 8) \
+                             dt).mapping(mapping, batch) \
                 != (exact.energy_j, exact.delay_s)
 
 
-@pytest.mark.parametrize("name", ["table1-tf", "phi35-moe"])
+@pytest.mark.parametrize("name", CONFIGS)
 def test_reference_graph_matches_the_program_graph(name):
     """The reference's graph of each configuration, at its benchmark
     sizes, has the program's layers, edges and expected-traffic scales."""
@@ -122,7 +127,7 @@ def test_reference_graph_matches_the_program_graph(name):
 
     cfg = _config(name)
     want = make_workload(harness.workload_spec(cfg))
-    got = graphs.build(cfg)
+    got = harness.load_reference(cfg).build(cfg)
     fields = ("kind", "K", "H", "W", "C", "R", "S", "stride", "groups",
               "bytes_per_elem", "n_inputs", "traffic_scale",
               "weight_traffic_scale")
@@ -135,7 +140,6 @@ def test_reference_graph_matches_the_program_graph(name):
 
 
 def test_window_order_is_seeded_inside_fixed_blocks():
-    import json
     mix = json.loads((ROOT / "chipbench" / "traffic"
                       / "sa-pool.json").read_text())
     pool, block = mix["pool"], mix["block"]
@@ -152,7 +156,6 @@ def test_window_order_is_seeded_inside_fixed_blocks():
 
 
 def test_window_meets_each_point_once_and_none_of_set_up():
-    import json
     from itertools import islice
     mix = json.loads((ROOT / "chipbench" / "traffic"
                       / "sa-pool.json").read_text())

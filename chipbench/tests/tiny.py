@@ -4,8 +4,8 @@
 ``chipbench/`` (without its tests), the repository's ``src/`` linked in, and
 a ``BENCHMARK.json`` that adds the ``tiny`` configuration and the
 ``tiny.sa`` / ``tiny.realize`` cells beside the real ones -- by adding
-files and entries only, as a later change would.  :func:`drive` runs one
-cell in a fresh process through ``drive.py``.
+files and entries only (:func:`add_cell`), as a later change would.
+:func:`drive` runs one cell in a fresh process through ``drive.py``.
 """
 
 from __future__ import annotations
@@ -63,40 +63,64 @@ TINY_REALIZE = {
 }
 
 
+# a 2x2-core candidate over 4 host devices: its T-Map plan has stages of
+# several layers, one of them with three source layers, and puts each
+# scores layer in another stage than the context layer that reads it
+TINY_MESH_CONFIG = dict(TINY_CONFIG, name="tiny-mesh", d_model=512,
+                        d_ff=1024, seq=64, batch=256)
+
+TINY_REALIZE4 = dict(TINY_REALIZE, arch=dict(TINY_REALIZE["arch"],
+                                             macs_per_core=9000),
+                     devices=4)
+
+
 def make_root(tmp: Path) -> Path:
     root = tmp / "checkout"
     shutil.copytree(ROOT / "chipbench", root / "chipbench",
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
     (root / "src").symlink_to(ROOT / "src")
-    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    cb = root / "chipbench"
-    (cb / "configs" / "tiny.json").write_text(json.dumps(TINY_CONFIG))
-    (cb / "traffic" / "tiny-pool.json").write_text(json.dumps(TINY_POOL))
-    (cb / "traffic" / "tiny-realize.json").write_text(json.dumps(TINY_REALIZE))
-    bench["configs"].append({"name": "tiny", "source": "tests",
-                             "file": "chipbench/configs/tiny.json",
-                             "reduced": [], "why": "tests"})
-    bench["workloads"] += [
-        {"name": "tiny.sa", "config": "tiny", "traffic": "tiny-pool",
-         "chips": 1, "why": "tests"},
-        {"name": "tiny.realize", "config": "tiny", "traffic": "tiny-realize",
-         "chips": 1, "why": "tests"}]
-    for m in bench["end_to_end"] + bench["per_layer"]:
-        for real, tiny in (("table1-tf.sa", "tiny.sa"),
-                           ("table1-tf.realize", "tiny.realize")):
-            if real in m.get("workloads", ()):
-                m["workloads"].append(tiny)
-    (root / "BENCHMARK.json").write_text(json.dumps(bench, indent=1))
+    shutil.copy(ROOT / "BENCHMARK.json", root / "BENCHMARK.json")
+    add_cell(root, "tiny.sa", TINY_CONFIG, "tiny-pool", TINY_POOL,
+             like="table1-tf.sa")
+    add_cell(root, "tiny.realize", TINY_CONFIG, "tiny-realize", TINY_REALIZE,
+             like="table1-tf.realize")
     return root
+
+
+def add_cell(root: Path, cell: str, config: Dict[str, Any], traffic: str,
+             mix: Dict[str, Any], like: str, chips: int = 1) -> None:
+    """Add ``cell`` to the checkout at ``root`` as files and entries only:
+    its configuration (unless there), its mix, its ``workloads`` entry,
+    and its name beside ``like`` in every metric's list of cells."""
+    cb = root / "chipbench"
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    name = config["name"]
+    if name not in {c["name"] for c in bench["configs"]}:
+        (cb / "configs" / f"{name}.json").write_text(json.dumps(config))
+        bench["configs"].append({"name": name, "source": "tests",
+                                 "file": f"chipbench/configs/{name}.json",
+                                 "reduced": [], "why": "tests"})
+    (cb / "traffic" / f"{traffic}.json").write_text(json.dumps(mix))
+    bench["workloads"].append({"name": cell, "config": name,
+                               "traffic": traffic, "chips": chips,
+                               "why": "tests"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if like in m.get("workloads", ()):
+            m["workloads"].append(cell)
+    (root / "BENCHMARK.json").write_text(json.dumps(bench, indent=1))
 
 
 def drive(root: Path, cell: str, fault: Optional[str] = None,
           control: bool = False, trace: bool = False, seconds: float = 1.0,
-          seed: int = SEED) -> Dict[str, Any]:
-    """One run of ``cell`` on the CPU in a fresh process; its result.  The
-    checkout's first run of a cell also starts the cell's cache fill."""
+          seed: int = SEED, host_devices: int = 1) -> Dict[str, Any]:
+    """One run of ``cell`` on the CPU in a fresh process, which sees
+    ``host_devices`` devices; its result.  The checkout's first run of a
+    cell also starts the cell's cache fill."""
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                JAX_COMPILATION_CACHE_DIR=str(root / ".jax_cache"))
+    if host_devices > 1:
+        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " --xla_force_host_"
+                            f"platform_device_count={host_devices}").strip()
     proc = subprocess.run(
         [sys.executable, str(ROOT / "chipbench" / "tests" / "drive.py"),
          str(root), cell, fault or "-", str(int(control)), str(int(trace)),
